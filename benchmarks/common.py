@@ -83,6 +83,8 @@ def write_bench_json(path: str = None, extra: dict = None,
 
 
 def run_subprocess_bench(code: str, devices: int, timeout=560) -> str:
+    from repro.launch.host import forbid_jax_children
+    forbid_jax_children("run_subprocess_bench")
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")

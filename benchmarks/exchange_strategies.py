@@ -56,7 +56,7 @@ if not pow2:
 for strat in strats:
     ex = Exchanger(strat, axis="data")
     f = jax.jit(shard_map(lambda t: ex.average(t), mesh=mesh,
-                          in_specs=(spec,), out_specs=spec, check_rep=False))
+                          in_specs=(spec,), out_specs=spec, check_vma=False))
     txt = f.lower(rep).compile().as_text()
     ops = {k: len(re.findall(k + r"(?:-start)?\\(", txt))
            for k in ("all-reduce", "collective-permute", "all-gather", "all-to-all")}

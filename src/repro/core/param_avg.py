@@ -58,10 +58,7 @@ from typing import Any, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-try:                                    # jax >= 0.6 top-level export
-    from jax import shard_map           # type: ignore[attr-defined]
-except ImportError:                     # jax 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map  # noqa: F401 — re-exported to core.steps
 
 STRATEGIES = ("all_reduce", "ring", "pairwise", "none")
 COMPRESSIONS = ("none", "bf16", "topk")

@@ -548,7 +548,7 @@ def make_mesh_param_avg_step(loss_fn: Callable, optimizer: Optimizer,
         sspec = replica_specs(state, axis)
         bspec = replica_specs(batch, axis)
         fn = shard_map(shard_step, mesh=mesh, in_specs=(sspec, bspec),
-                       out_specs=(sspec, P()), check_rep=False)
+                       out_specs=(sspec, P()), check_vma=False)
         return fn(state, batch)
 
     return step

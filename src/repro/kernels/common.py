@@ -50,8 +50,11 @@ import jax
 
 # shape-keyed winner cache: key -> blocks tuple
 _CACHE: Dict[tuple, tuple] = {}
-# how many measured autotune sweeps ran (introspection / tests)
+# how many measured autotune sweeps ran, and cache hits (introspection /
+# tests)
 _STATS = {"measured": 0, "hits": 0}
+# (key, candidate, error) for every refused candidate, oldest first
+_REFUSALS: list = []
 
 SUBLANE = 8           # TPU fp32 sublane count — block floor
 LANE = 128            # TPU lane count — preferred alignment
@@ -101,11 +104,13 @@ def autotune_enabled(interpret: bool, override: Optional[bool] = None,
 
 def clear_cache() -> None:
     _CACHE.clear()
+    _REFUSALS.clear()
     _STATS["measured"] = _STATS["hits"] = 0
 
 
 def cache_info() -> dict:
-    return {"entries": len(_CACHE), **_STATS}
+    return {"entries": len(_CACHE), **_STATS, "refused": len(_REFUSALS),
+            "errors": list(_REFUSALS)}
 
 
 def cache_state() -> dict:
@@ -140,10 +145,12 @@ def autotune(key: tuple, candidates: Sequence[tuple],
              measure: Optional[Callable[[tuple], float]]) -> tuple:
     """Return the cached winner for ``key``, measuring once on a miss.
 
-    ``measure(candidate) -> seconds``; exceptions disqualify a candidate
-    (e.g. a blocking the compiler rejects) rather than failing the tune.
-    A single candidate is cached without measuring (``measure`` may be
-    None then).
+    ``measure(candidate) -> seconds``; an exception disqualifies a
+    candidate (e.g. a blocking the compiler refuses): it is counted in
+    ``cache_info()["refused"]`` and its error kept in
+    ``cache_info()["errors"]``.  When every candidate is refused the tune
+    raises with the compiler's messages — nothing is cached.  A single
+    candidate is cached without measuring (``measure`` may be None then).
     """
     if key in _CACHE:
         _STATS["hits"] += 1
@@ -151,23 +158,39 @@ def autotune(key: tuple, candidates: Sequence[tuple],
     best, best_t = candidates[0], float("inf")
     if len(candidates) > 1:
         _STATS["measured"] += 1
+        errors = []
         for cand in candidates:
             try:
                 t = measure(cand)
-            except Exception:
+            except Exception as e:      # a refused blocking: kept below
+                err = f"{type(e).__name__}: {e}"
+                _REFUSALS.append((key, cand, err))
+                errors.append(f"{cand}: {err}")
                 continue
             if t < best_t:
                 best, best_t = cand, t
+        if best_t == float("inf"):
+            raise RuntimeError(f"autotune {key}: every candidate was "
+                               "refused:\n" + "\n".join(errors))
     _CACHE[key] = best
     return best
 
 
 def time_call(fn, *args, iters: int = 3) -> float:
-    """Mean wall-time per call in seconds (compile+warm excluded)."""
-    jax.block_until_ready(fn(*args))          # compile + warm
+    """Mean wall-time per ``fn(*args)`` call in seconds (compile and
+    warm-up excluded).
+
+    Tuners run while the caller's step is being traced, where a plain call
+    would be staged into that program instead of running, and the clock
+    would time tracing.  So ``fn`` is compiled ahead of time and its
+    executable called directly — that always runs — with ``args`` put on
+    the device once (by a compiled identity, for the same reason)."""
+    staged = jax.jit(lambda *a: a).lower(*args).compile()(*args)
+    run = jax.jit(fn).lower(*staged).compile()
+    jax.block_until_ready(run(*staged))       # warm
     t0 = time.perf_counter()
     for _ in range(iters):
-        out = fn(*args)
+        out = run(*staged)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters
 

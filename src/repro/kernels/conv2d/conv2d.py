@@ -11,12 +11,14 @@ two Pallas programs:
     This is the GEMM stage of the *reference* two-stage im2col path.
 
 ``conv2d_fused``
-    Implicit-GEMM convolution: the grid walks (batch, M-tile, N-tile)
-    output tiles and each program instance gathers its input window
-    slices directly from the VMEM-staged ``(H, W, C)`` operand — the
+    Implicit-GEMM convolution: the grid walks (group, batch, N-tile,
+    row-tile) output tiles and each program instance loads its input
+    windows directly from the VMEM-staged ``(H, W, C)`` operand — the
     ``(B*OH*OW, K*K*C)`` im2col patch tensor never exists in HBM.  The
-    K*K reduction is unrolled in-kernel as static strided slices feeding
-    the MXU, so all three grid axes are parallel.
+    wrapper turns stride > 1 into stride 1 by space-to-depth, so the
+    per-tap windows are plain ``pl.ds`` loads from the ref (the TPU
+    compiler refuses value-level strided gathers); the tap reduction is
+    unrolled in-kernel feeding the MXU, so every grid axis is parallel.
 
 Both are differentiable via ``jax.custom_vjp`` (Pallas calls have no
 automatic AD): ``matmul_bias`` back-propagates through two more Pallas
@@ -36,11 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import SUBLANE
 from repro.kernels.conv2d import tune
-
-# jax 0.4.x names it TPUCompilerParams; newer jax renames to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 
 # ---------------------------------------------------------------------------
@@ -91,7 +90,7 @@ def _matmul_bias_impl(x, w, b, bm, bk, bn, relu, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, wp, bp)
@@ -149,49 +148,50 @@ def matmul_bias(x, w, b, *, bm: int = None, bk: int = None, bn: int = None,
 # fused implicit-GEMM convolution
 # ---------------------------------------------------------------------------
 
-def _conv_fused_kernel(x_ref, w_ref, b_ref, o_ref, *, kernel: int,
-                       stride: int, oh: int, ow: int, m_pad: int,
-                       relu: bool):
-    """One (batch b, M-tile i, group-walking N-tile j) output tile.
+def _conv_fused_kernel(x_ref, w_ref, b_ref, o_ref, *, taps: int, tr: int,
+                       owp: int, relu: bool):
+    """One (group g, image b, N-tile j, row-tile i) output tile.
 
-    x_ref (1, Hp, Wp, 1, Cg) — ONE group's input-channel slice of the
-    padded image, staged in VMEM (the BlockSpec index map picks the
-    group, so a program never sees the other groups' channels);
-    w_ref (1, K*K*Cg, bn) — that group's weight slab; b_ref (1, 1, bn);
-    o_ref (1, bm, 1, bn).
+    x_ref (Hs, Ws, C) — ONE image's ONE group, padded and (for stride > 1)
+    space-to-depth'd, so the conv it sees is always stride 1 with a
+    ``taps``-wide window (the BlockSpec index map picks the group, so a
+    program never sees the other groups' channels);
+    w_ref (taps*taps, C, bn) — that group's per-tap weight slabs;
+    b_ref (1, bn); o_ref (tr*owp, bn) — ``tr`` whole output rows.
 
-    Patch rows are gathered on the fly: for each static kernel offset
-    (kh, kw) the strided window slice of the image IS the (M, Cg) slab
-    of the im2col matrix belonging to that offset, so the reduction is
-    K*K unrolled (bm, Cg) @ (Cg, bn) MXU dots — implicit GEMM.  Grouped
-    convolution is just the N axis walking block-diagonal tiles: the
-    grid's j axis enumerates (group, in-group N-tile) pairs and the
-    index maps route each j to its diagonal block — no per-group Python
-    loop, no HBM blowup.
+    Patch rows are loaded on the fly: for each static tap (kh, kw) the
+    ref window ``x[i*tr+kh : +tr, kw : kw+owp]`` IS the (tr*owp, C) slab
+    of the im2col matrix belonging to that tap, so the reduction is
+    taps² unrolled (tr*owp, C) @ (C, bn) MXU dots — implicit GEMM.  The
+    row offset is a ``pl.ds`` on the ref's leading axis, the column
+    offset a static slice, and ``owp`` is a sublane multiple, so the
+    (tr, owp, C) -> (tr*owp, C) collapse is layout-free.
     """
-    i = pl.program_id(1)
-    xv = x_ref[0, :, :, 0, :]
-    c = xv.shape[-1]
-    bm, bn = o_ref.shape[1], o_ref.shape[3]
-    span_h = (oh - 1) * stride + 1
-    span_w = (ow - 1) * stride + 1
-    acc = jnp.zeros((bm, bn), jnp.float32)
-    for kh in range(kernel):
-        for kw in range(kernel):
-            xs = xv[kh:kh + span_h:stride, kw:kw + span_w:stride, :]
-            xs = xs.reshape(oh * ow, c)
-            if m_pad != oh * ow:
-                xs = jnp.pad(xs, ((0, m_pad - oh * ow), (0, 0)))
-            blk = jax.lax.dynamic_slice_in_dim(xs, i * bm, bm, 0)
-            q = kh * kernel + kw
+    r0 = pl.program_id(3) * tr
+    c = x_ref.shape[-1]
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for kh in range(taps):
+        for kw in range(taps):
+            xs = x_ref[pl.ds(r0 + kh, tr), pl.ds(kw, owp), :]
             acc += jax.lax.dot(
-                blk.astype(jnp.float32),
-                w_ref[0, q * c:(q + 1) * c, :].astype(jnp.float32),
+                xs.reshape(tr * owp, c).astype(jnp.float32),
+                w_ref[kh * taps + kw].astype(jnp.float32),
                 preferred_element_type=jnp.float32)
-    y = acc + b_ref[0].astype(jnp.float32)
+    y = acc + b_ref[...].astype(jnp.float32)
     if relu:
         y = jnp.maximum(y, 0.0)
-    o_ref[0, :, 0, :] = y.astype(o_ref.dtype)
+    o_ref[...] = y.astype(o_ref.dtype)
+
+
+def _row_tiling(oh: int, ow: int, bm: int, dtype) -> tuple:
+    """(tr, owp, n_tiles): M is tiled by whole output rows.  ``owp`` pads
+    the output width to the dtype's sublane multiple; ``tr`` rows of it
+    fill at most ``bm`` M entries, balanced so the last tile wastes less
+    than one tile's worth of rows."""
+    sub = SUBLANE * max(1, 4 // jnp.dtype(dtype).itemsize)
+    owp = -(-ow // sub) * sub
+    n_tiles = -(-oh // max(1, bm // owp))
+    return -(-oh // n_tiles), owp, n_tiles
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "stride", "oh", "ow",
@@ -201,44 +201,61 @@ def _conv_fused_impl(x, w, bias, kernel, stride, oh, ow, bm, bn, relu,
                      groups, interpret):
     b_, hp, wp, cin = x.shape
     cout = w.shape[-1]
+    s = stride
     cig = cin // groups          # input channels per group
     npg = cout // groups         # output channels per group
-    m = oh * ow
-    m_pad = -(-m // bm) * bm
     npg_pad = -(-npg // bn) * bn     # pad per group so bn tiles never
-    kkc = kernel * kernel * cig      # straddle a group boundary
-    # (K,K,Cg,Cout) -> (G, K*K*Cg, npg) stacked per-group weight slabs;
-    # Cout is group-major (group g owns channels [g*npg, (g+1)*npg))
-    wmat = w.reshape(kkc, groups, npg).transpose(1, 0, 2)
-    bvec = bias.reshape(groups, 1, npg)
+    tiles_pg = npg_pad // bn         # straddle a group boundary
+    taps = -(-kernel // s)           # window width after space-to-depth
+    tr, owp, n_i = _row_tiling(oh, ow, bm, x.dtype)
+    ohp = n_i * tr
+    hs, ws = ohp + taps - 1, owp + taps - 1
+    cs = s * s * cig                 # channels per group after s2d
+
+    # pad (or crop rows no output reads) to whole s x s cells, then
+    # space-to-depth with the group on the leading axis:
+    # (B, hs*s, ws*s, G*Cg) -> (G, B, hs, ws, s*s*Cg), channels (ph, pw, c)
+    x = jax.lax.pad(x, jnp.zeros((), x.dtype),
+                    [(0, 0, 0), (0, hs * s - hp, 0), (0, ws * s - wp, 0),
+                     (0, 0, 0)])
+    xg = (x.reshape(b_, hs, s, ws, s, groups, cig)
+          .transpose(5, 0, 1, 3, 2, 4, 6).reshape(groups, b_, hs, ws, cs))
+    # (K,K,Cg,Cout) -> zero taps up to taps*s -> (G, taps², s*s*Cg, npg):
+    # tap (kh', kw') channel (ph, pw, c) is original tap (kh'*s+ph, kw'*s+pw)
+    e = taps * s - kernel
+    wq = jnp.pad(w, ((0, e), (0, e), (0, 0), (0, 0)))
+    wq = (wq.reshape(taps, s, taps, s, cig, groups, npg)
+          .transpose(5, 0, 2, 1, 3, 4, 6)
+          .reshape(groups, taps * taps, cs, npg))
+    bvec = bias.reshape(groups, npg)
     if npg_pad != npg:
-        wmat = jnp.pad(wmat, ((0, 0), (0, 0), (0, npg_pad - npg)))
-        bvec = jnp.pad(bvec, ((0, 0), (0, 0), (0, npg_pad - npg)))
-    xg = x.reshape(b_, hp, wp, groups, cig)
-    tiles_pg = npg_pad // bn         # N-tiles per diagonal block
+        wq = jnp.pad(wq, ((0, 0), (0, 0), (0, 0), (0, npg_pad - npg)))
+        bvec = jnp.pad(bvec, ((0, 0), (0, npg_pad - npg)))
+    bvec = bvec.reshape(1, groups * npg_pad)
 
     out = pl.pallas_call(
-        functools.partial(_conv_fused_kernel, kernel=kernel, stride=stride,
-                          oh=oh, ow=ow, m_pad=m_pad, relu=relu),
-        grid=(b_, m_pad // bm, groups * tiles_pg),
+        functools.partial(_conv_fused_kernel, taps=taps, tr=tr, owp=owp,
+                          relu=relu),
+        grid=(groups, b_, tiles_pg, n_i),
         in_specs=[
-            pl.BlockSpec((1, hp, wp, 1, cig),
-                         lambda b, i, j: (b, 0, 0, j // tiles_pg, 0)),
-            pl.BlockSpec((1, kkc, bn),
-                         lambda b, i, j: (j // tiles_pg, 0, j % tiles_pg)),
-            pl.BlockSpec((1, 1, bn),
-                         lambda b, i, j: (j // tiles_pg, 0, j % tiles_pg)),
+            pl.BlockSpec((None, None, hs, ws, cs),
+                         lambda g, b, j, i: (g, b, 0, 0, 0)),
+            pl.BlockSpec((None, taps * taps, cs, bn),
+                         lambda g, b, j, i: (g, 0, 0, j)),
+            pl.BlockSpec((1, bn), lambda g, b, j, i: (0, g * tiles_pg + j)),
         ],
         out_specs=pl.BlockSpec(
-            (1, bm, 1, bn),
-            lambda b, i, j: (b, i, j // tiles_pg, j % tiles_pg)),
-        out_shape=jax.ShapeDtypeStruct((b_, m_pad, groups, npg_pad),
+            (None, tr * owp, bn),
+            lambda g, b, j, i: (b, i, g * tiles_pg + j)),
+        out_shape=jax.ShapeDtypeStruct((b_, ohp * owp, groups * npg_pad),
                                        x.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 4),
         interpret=interpret,
-    )(xg, wmat, bvec)
-    out = out if (m_pad == m and npg_pad == npg) else out[:, :m, :, :npg]
+    )(xg, wq, bvec)
+    out = out.reshape(b_, ohp, owp, groups, npg_pad)
+    if (ohp, owp, npg_pad) != (oh, ow, npg):
+        out = out[:, :oh, :ow, :, :npg]
     return out.reshape(b_, oh, ow, cout)
 
 

@@ -74,14 +74,14 @@ def matmul_blocks(m: int, k: int, n: int, dtype, *, interpret: bool,
     def measure(c):
         bm, bk, bn = c
         return time_call(
-            lambda: _k.matmul_bias(x, w, b, bm=bm, bk=bk, bn=bn,
-                                   interpret=False))
+            lambda x, w, b: _k.matmul_bias(x, w, b, bm=bm, bk=bk, bn=bn,
+                                           interpret=False), x, w, b)
     return common.autotune(key, sorted(cands), measure)
 
 
-# fused conv tiles M = OH*OW; a larger cap than the matmul's 128 keeps
-# the in-kernel whole-image window gather from being repeated across
-# many M-tiles (each M-tile program re-gathers the windows it slices)
+# fused conv tiles M = OH*OW by whole output rows; bm is the budget a
+# tile's rows fill (conv2d._row_tiling).  A larger cap than the matmul's
+# 128 means fewer, taller tiles over the VMEM-resident image.
 _CONV_BM_CAP = 512
 
 
@@ -92,11 +92,16 @@ def conv_blocks(b: int, oh: int, ow: int, kernel: int, cin: int, cout: int,
     in-kernel, so there is no bk).  ``groups`` is part of the cache key —
     a grouped layer tiles N per diagonal block (Cout/G wide), so its
     winning blocking is NOT the ungrouped layer's — and bn defaults to
-    the per-group output width, never a whole-Cout tile.  ``w_dtype``
-    widens the key to the (x, w) dtype tuple for mixed-precision calls
-    (see ``matmul_blocks``)."""
+    the per-group output width, never a whole-Cout tile.  A compiled
+    grouped conv takes a whole lane (128) at least: its bn tile is one
+    of several along the output's lane axis, which Mosaic only splits at
+    lane multiples.  ``w_dtype`` widens the key to the (x, w) dtype tuple
+    for mixed-precision calls (see ``matmul_blocks``)."""
     m = oh * ow
-    default = (pow2_clip(m, _CONV_BM_CAP), pow2_clip(cout // groups, LANE))
+    bn = pow2_clip(cout // groups, LANE)
+    if groups > 1 and not interpret:
+        bn = LANE
+    default = (pow2_clip(m, _CONV_BM_CAP), bn)
     dt_key = str(dtype) if w_dtype is None else (str(dtype), str(w_dtype))
     key = ("conv", b, oh, ow, kernel, cin, cout, stride, groups, dt_key)
     if not _autotune_enabled(interpret, autotune):
@@ -117,7 +122,7 @@ def conv_blocks(b: int, oh: int, ow: int, kernel: int, cin: int, cout: int,
     def measure(c):
         bm, bn = c
         return time_call(
-            lambda: _ops.conv2d_fused(x, wt, stride=stride, padding=0,
-                                      groups=groups, bm=bm, bn=bn,
-                                      interpret=False))
+            lambda x, wt: _ops.conv2d_fused(x, wt, stride=stride, padding=0,
+                                            groups=groups, bm=bm, bn=bn,
+                                            interpret=False), x, wt)
     return common.autotune(key, sorted(cands), measure)

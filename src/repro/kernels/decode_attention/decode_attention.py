@@ -31,10 +31,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import SUBLANE, pow2_clip, resolve_interpret
 
-# jax 0.4.x names it TPUCompilerParams; newer jax renames to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG = -1e30
 
 
@@ -136,7 +132,7 @@ def _decode_impl(q, k, v, ks, vs, pos, n_kv_heads, window, scale, bk,
                           cap=cap, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, gp, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos, *args)
@@ -198,7 +194,7 @@ def _decode_impl_table(q, k, v, ks, vs, pos, table, n_kv_heads, window,
                           cap=cap, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, gp, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos, table, *args)

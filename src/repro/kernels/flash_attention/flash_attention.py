@@ -41,10 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import pow2_clip, resolve_interpret
 
-# jax 0.4.x names it TPUCompilerParams; newer jax renames to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG = -1e30
 
 
@@ -157,7 +153,7 @@ def _flash_fwd_impl(q, k, v, n_q_heads, n_kv_heads, causal, window, scale,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -284,7 +280,7 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, n_q_heads, n_kv_heads, causal,
         out_specs=pl.BlockSpec((1, bq, hd), q_map),
         out_shape=jax.ShapeDtypeStruct((bhq, s, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -320,7 +316,7 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, n_q_heads, n_kv_heads, causal,
                    jax.ShapeDtypeStruct((bhq, s, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)

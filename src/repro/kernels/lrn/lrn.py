@@ -28,9 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import common
 from repro.kernels.lrn import ref as lrn_ref_mod
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _ROW_CAP = 512          # M-tile cap, same scale as the conv kernel's
 
 
@@ -67,7 +64,7 @@ def _lrn_impl(x, n, alpha, beta, k, bm, interpret):
         in_specs=[pl.BlockSpec((bm, c_pad), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, c_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, c_pad), x.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xm)
     if (m_pad, c_pad) != (m, c):

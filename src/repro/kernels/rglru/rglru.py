@@ -32,10 +32,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import pow2_clip, resolve_interpret
 
-# jax 0.4.x names it TPUCompilerParams; newer jax renames to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _rglru_kernel(a_ref, b_ref, h_ref, state, *, chunk: int):
     ci = pl.program_id(2)
@@ -77,7 +73,7 @@ def _rglru_impl(a, b, chunk, bd, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bsz, t, d), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -33,10 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import pow2_clip, resolve_interpret
 from repro.kernels.rwkv6 import ref
 
-# jax 0.4.x names it TPUCompilerParams; newer jax renames to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref,
                 s_scratch, *, chunk: int, n_chunks: int):
@@ -107,7 +103,7 @@ def _wkv_impl(r, k, v, w, u, chunk, interpret):
         out_shape=[jax.ShapeDtypeStruct((b * h, t, kk), r.dtype),
                    jax.ShapeDtypeStruct((b * h, kk, kk), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((kk, kk), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf)

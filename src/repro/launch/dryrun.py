@@ -230,17 +230,8 @@ def parse_collectives(hlo_text: str) -> dict:
     return out
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """jax <= 0.4.x returns a one-element list of dicts; newer jax returns
-    the dict itself."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def analyze(compiled) -> dict:
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     return {
         "flops": float(cost.get("flops", 0.0)),
         "bytes": float(cost.get("bytes accessed", 0.0)),

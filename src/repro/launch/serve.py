@@ -33,9 +33,10 @@ or launched by hand for a multi-host layout.
 import os
 
 if os.environ.get("REPRO_DEVICES"):
-    os.environ["XLA_FLAGS"] = (
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
         "--xla_force_host_platform_device_count="
-        + os.environ["REPRO_DEVICES"])
+        + os.environ["REPRO_DEVICES"])))
 
 # ruff: noqa: E402
 import argparse
@@ -48,6 +49,7 @@ import numpy as np
 from repro import models
 from repro.configs import ALEXNET, ALEXNET_SMOKE, get_config, reduced
 from repro.kernels.common import KernelPolicy
+from repro.launch.host import enable_compile_cache
 from repro.launch.mesh import make_replica_mesh
 from repro.numerics import get_policy
 from repro.serving import Request, Router, ServingEngine
@@ -278,6 +280,7 @@ def run_tier(args):
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     if args.role in ("engine", "decode", "prefill"):
         run_worker(args)
         return

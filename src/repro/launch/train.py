@@ -7,8 +7,8 @@ throughput metrics (docs/training.md).
 
 Runs the paper's parameter-averaging data parallelism end-to-end on this
 host's devices (set REPRO_DEVICES=N to fan out over N host devices — this
-driver sets XLA_FLAGS itself when the variable is present, BEFORE importing
-jax, so it must stay the first import in the process).
+driver appends the device count to XLA_FLAGS when the variable is present,
+BEFORE importing jax, so it must stay the first import in the process).
 
 NOTE: without --smoke, --arch alexnet now means the FULL 227px net (the
 seed silently swapped in the smoke config whenever --image-size < 128);
@@ -29,9 +29,10 @@ Examples:
 import os
 
 if os.environ.get("REPRO_DEVICES"):
-    os.environ["XLA_FLAGS"] = (
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
         "--xla_force_host_platform_device_count="
-        + os.environ["REPRO_DEVICES"])
+        + os.environ["REPRO_DEVICES"])))
 
 # ruff: noqa: E402
 import argparse
@@ -47,6 +48,7 @@ from repro.core import (ExchangeConfig, init_param_avg_state, make_eval_step,
                         make_mesh_param_avg_step, make_param_avg_step,
                         replica_spread, reshape_for_replicas)
 from repro.kernels.common import KernelPolicy
+from repro.launch.host import enable_compile_cache
 from repro.launch.mesh import make_replica_mesh
 from repro.numerics import get_policy
 from repro.sharding.specs import replica_sharding, state_sharding
@@ -68,6 +70,7 @@ class Build:
     make_eval_batches: callable       # () -> fresh held-out iterator
     eval_metric_fn: callable          # (params, batch) -> {name: scalar}
     plateau_metric: str               # the metric the LR controller tracks
+    kernels: dict                     # op -> the backend it resolved to
 
 
 def make_policy(args) -> KernelPolicy:
@@ -118,9 +121,17 @@ def build_lm(args, numerics) -> Build:
     def loss(params, batch):
         return models.loss_fn(params, cfg, batch)
 
+    from repro.models import attention, rglru, rwkv
+    kernels = {} if cfg.attention_free else {
+        "attention": attention.resolve_impl(cfg, sq=args.seq_len,
+                                            sk=args.seq_len)}
+    if cfg.family == "ssm":
+        kernels["rwkv6"] = rwkv.resolve_wkv_impl(cfg)
+    if cfg.family == "hybrid":
+        kernels["rglru"] = rglru.resolve_rglru_impl(cfg)
     return Build(cfg, lambda r: models.init(r, cfg), loss, make_stream,
                  make_eval_batches, lm_metrics(cfg),
-                 plateau_metric="loss")
+                 plateau_metric="loss", kernels=kernels)
 
 
 def build_alexnet(args, error, numerics) -> Build:
@@ -159,9 +170,11 @@ def build_alexnet(args, error, numerics) -> Build:
         return alexnet_mod.loss_fn(params, cfg, batch["images"],
                                    batch["labels"])
 
+    kernels = {"conv2d": alexnet_mod.resolve_conv_backend(cfg),
+               "lrn": alexnet_mod.resolve_lrn_backend(cfg)}
     return Build(cfg, lambda r: alexnet_mod.init(r, cfg), loss, make_stream,
                  make_eval_batches, alexnet_metrics(cfg),
-                 plateau_metric="top1_err")
+                 plateau_metric="top1_err", kernels=kernels)
 
 
 def make_controller(args):
@@ -179,7 +192,7 @@ def make_controller(args):
         threshold=args.plateau_threshold)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="alexnet")
     ap.add_argument("--smoke", action="store_true")
@@ -290,7 +303,24 @@ def main():
                     help="JSONL trace path (train/eval/summary records, "
                     "docs/training.md); implies per-step host sync")
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap
+
+
+@dataclasses.dataclass
+class Run:
+    """What ``main`` trained: the session (``session.step_fn`` is the
+    jitted step it ran), its result, the backend each kernel op resolved
+    to, and the final replica spread."""
+    session: TrainSession
+    result: object
+    kernels: dict
+    spread: float
+
+
+def main(argv=None) -> Run:
+    """Parse ``argv`` (default: the command line) and train."""
+    ap = build_parser()
+    args = ap.parse_args(argv)
 
     if args.schedule == "plateau" and args.eval_every <= 0:
         ap.error("--schedule plateau needs --eval-every > 0 (the plateau "
@@ -298,6 +328,7 @@ def main():
     if args.resume and not args.ckpt_dir:
         ap.error("--resume needs --ckpt-dir")
 
+    enable_compile_cache()
     n_dev = jax.device_count()
     mp = args.model_parallel
     n_rep = args.replicas or (n_dev // mp if mp > 1 else n_dev)
@@ -427,7 +458,7 @@ def main():
           f"devices={n_dev} model_parallel={mp} "
           f"engine={engine} exchange={exch.describe()} "
           f"replica_exec={args.replica_exec} staging={args.staging} "
-          f"kernels={make_policy(args).describe()} "
+          f"kernels={build.kernels} "
           f"numerics={npol.describe()}"
           + (f" resume_from={args.ckpt_dir}" if args.resume else ""))
     result = session.run()
@@ -442,6 +473,7 @@ def main():
           f"{result.losses[-1][1] if result.losses else float('nan'):.4f}; "
           f"replica spread {spread:.2e}" + through
           + (f"; lr drops at {result.lr_drops}" if result.lr_drops else ""))
+    return Run(session, result, build.kernels, spread)
 
 
 if __name__ == "__main__":

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint, models
+from repro.launch.host import forbid_jax_children
 from repro.serving import engine as engine_mod
 from repro.serving import sampling
 from repro.serving.engine import Request
@@ -364,6 +365,7 @@ def spawn_worker(role: str, model_args: List[str], *, port: Optional[int] = None
     handle.  ``model_args`` are plain serve.py flags — the same flags
     that describe a single-process engine describe each instance, which
     is what keeps a tier homogeneous (drain/handoff requires it)."""
+    forbid_jax_children("spawn_worker")
     port = port or free_port()
     cmd = [sys.executable, "-m", "repro.launch.serve",
            "--role", role, "--port", str(port)] + list(model_args)

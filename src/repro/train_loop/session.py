@@ -94,6 +94,7 @@ class TrainSession:
                  run_meta: Optional[dict] = None):
         self.state = state
         self.build_step = build_step
+        self.step_fn = None           # the jitted step run() last built
         self.make_stream = make_stream
         self.controller = schedules.as_controller(
             controller if controller is not None
@@ -213,7 +214,7 @@ class TrainSession:
                                  staging=self.staging,
                                  device_put=self.device_put)
             sched_fn = self.controller.schedule()
-            step_fn = self.build_step(sched_fn)
+            step_fn = self.step_fn = self.build_step(sched_fn)
             # a metrics trace needs the loss + honest wall time every step,
             # which costs a host sync per step; without it, sync only at
             # log/eval boundaries and keep jax's async dispatch pipelined
@@ -254,7 +255,7 @@ class TrainSession:
                 if self.eval_every and (i + 1) % self.eval_every == 0:
                     if self._run_eval(i + 1, writer, result):
                         sched_fn = self.controller.schedule()
-                        step_fn = self.build_step(sched_fn)
+                        step_fn = self.step_fn = self.build_step(sched_fn)
                         compiling = True
                 if self.ckpt_dir and self.ckpt_every and \
                         (i + 1) % self.ckpt_every == 0:

@@ -2,6 +2,7 @@
 lax.conv oracle, plus gradient parity, the no-HBM-im2col proof, and the
 aligned-matmul no-pad guarantee."""
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -46,9 +47,9 @@ def _collect_shapes(jaxpr, out):
                 out.add(tuple(aval.shape))
         for p in eqn.params.values():
             for sub in (p if isinstance(p, (list, tuple)) else (p,)):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     _collect_shapes(sub.jaxpr, out)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     _collect_shapes(sub, out)
     return out
 
@@ -58,9 +59,9 @@ def _primitives(jaxpr, out):
         out.add(eqn.primitive.name)
         for p in eqn.params.values():
             for sub in (p if isinstance(p, (list, tuple)) else (p,)):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     _primitives(sub.jaxpr, out)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     _primitives(sub, out)
     return out
 

@@ -51,6 +51,28 @@ def test_autotune_skips_failing_candidates():
         return 1.0
 
     assert tune.autotune(("k",), [(8,), (16,)], measure) == (16,)
+    # the refusal is counted and its error kept, not swallowed
+    info = tune.cache_info()
+    assert info["refused"] == 1
+    assert info["errors"] == [(("k",), (8,),
+                               "RuntimeError: compiler rejected blocking")]
+    tune.clear_cache()
+    assert tune.cache_info()["refused"] == 0
+    assert tune.cache_info()["errors"] == []
+
+
+def test_autotune_raises_when_every_candidate_refused():
+    """No runnable blocking: the tune raises with the compiler's message
+    and caches nothing (it used to cache candidates[0] silently)."""
+    tune.clear_cache()
+
+    def measure(cand):
+        raise ValueError(f"block {cand} exceeds VMEM")
+
+    with pytest.raises(RuntimeError, match=r"block \(16,\) exceeds VMEM"):
+        tune.autotune(("k",), [(8,), (16,)], measure)
+    info = tune.cache_info()
+    assert info["entries"] == 0 and info["refused"] == 2
     tune.clear_cache()
 
 
@@ -70,6 +92,25 @@ def test_blocks_clip_to_problem_shape():
     tune.matmul_blocks(100, 70, 50, "float32", interpret=True)
     assert tune.cache_info()["hits"] == before + 1
     tune.clear_cache()
+
+
+def test_time_call_runs_on_device_under_trace():
+    """Tuners measure at trace time: time_call must execute its calls
+    before it returns, not stage them into the program being traced."""
+    import numpy as np
+    from repro.kernels import common
+    ran = []
+
+    def probe(x):
+        jax.debug.callback(lambda: ran.append(1))
+        return x * 2
+
+    def traced(y):
+        common.time_call(probe, np.ones(3, np.float32), iters=2)
+        assert len(ran) == 3              # warm-up + 2 timed calls
+        return y
+
+    jax.jit(traced)(1.0)
 
 
 @pytest.mark.parametrize("env,measured", [("0", 0), ("1", 1)])

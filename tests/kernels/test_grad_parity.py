@@ -13,6 +13,7 @@ the (S, S) score matrix, and the registry-driven parity loop that gives
 any newly-registered kernel forward+grad coverage for free.
 """
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -80,9 +81,9 @@ def _collect_shapes(jaxpr, out):
                 out.add(tuple(aval.shape))
         for p in eqn.params.values():
             for sub in (p if isinstance(p, (list, tuple)) else (p,)):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     _collect_shapes(sub.jaxpr, out)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     _collect_shapes(sub, out)
     return out
 

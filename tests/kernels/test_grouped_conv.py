@@ -8,6 +8,7 @@ materializes the (B*OH*OW, K*K*Cin) patch tensor, and that NOTHING inside
 the Pallas kernel is sized by the full channel counts — each tile reads
 only its group's input slice (the no-cross-group-reads acceptance)."""
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -115,9 +116,9 @@ def _collect_shapes(jaxpr, out):
                 out.add(tuple(aval.shape))
         for p in eqn.params.values():
             for sub in (p if isinstance(p, (list, tuple)) else (p,)):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     _collect_shapes(sub.jaxpr, out)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     _collect_shapes(sub, out)
     return out
 
@@ -130,9 +131,9 @@ def _pallas_inner_shapes(jaxpr, out):
             _collect_shapes(getattr(inner, "jaxpr", inner), out)
         for p in eqn.params.values():
             for sub in (p if isinstance(p, (list, tuple)) else (p,)):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     _pallas_inner_shapes(sub.jaxpr, out)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     _pallas_inner_shapes(sub, out)
     return out
 

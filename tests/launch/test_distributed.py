@@ -154,7 +154,7 @@ for arch in ("olmo-1b", "mixtral-8x7b", "rwkv6-7b"):
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=128, global_batch=8)
     lowered = D.build_lowered(cfg, shape, mesh, "train", ("data",), None, 2, "qloop")
     compiled = lowered.compile()
-    assert D.cost_analysis_dict(compiled)["flops"] > 0
+    assert compiled.cost_analysis()["flops"] > 0
     shape_d = dataclasses.replace(SHAPES["decode_32k"], seq_len=64, global_batch=4)
     lowered = D.build_lowered(cfg, shape_d, mesh, "decode", None, None, 1, "qloop")
     lowered.compile()
