@@ -17,6 +17,15 @@ R = #replicas; batches are (R, per_replica_batch, ...).  The reference
 engine vmaps over axis 0; the mesh engine shards axis 0 one-replica-per-
 shard so each program instance owns exactly one replica — the paper's
 one-model-per-GPU memory layout, literally.
+
+Phases: every engine traces its step under three ``jax.named_scope``s,
+which reach the compiled HLO's ``op_name`` metadata and cost nothing at
+run time.  ``train.loss`` wraps the loss inside ``value_and_grad``, so
+the forward reads ``jvp(train.loss)`` and the backward
+``transpose(jvp(train.loss))``; ``train.update`` wraps the unscale and
+finite check, the optimizer update and the skip select;
+``train.exchange`` wraps the cross-replica averaging and the loss's
+cross-replica mean.  ``bench/phases.py`` reads them.
 """
 from __future__ import annotations
 
@@ -33,6 +42,13 @@ from repro.core.param_avg import (AxisName, ExchangeConfig, Exchanger,
 from repro.numerics import (NumericsPolicy, all_finite, cast_floats,
                             init_loss_scale_state, next_loss_scale_state)
 from repro.optim.optimizers import Optimizer, apply_updates
+
+
+# the phases' named scopes (module docstring); no name may hold
+# "transpose" or "dot_general", which bench/xplane.py's conv-grad test reads
+LOSS_SCOPE = "train.loss"
+UPDATE_SCOPE = "train.update"
+EXCHANGE_SCOPE = "train.exchange"
 
 
 @jax.tree_util.register_dataclass
@@ -121,6 +137,7 @@ def _make_loss_and_grad(loss_fn: Callable, microbatch: int,
     """
     cdt = None if compute_dtype is None else jnp.dtype(compute_dtype)
 
+    @jax.named_scope(LOSS_SCOPE)
     def run_loss(params, batch, scale):
         if cdt is not None:
             # float inputs (images, frames, patch embeds) follow the
@@ -137,7 +154,8 @@ def _make_loss_and_grad(loss_fn: Callable, microbatch: int,
         if microbatch == 1:
             if cdt is None and scale is None:
                 # the pre-policy trace, bit-equal
-                return jax.value_and_grad(loss_fn)(params, batch)
+                return jax.value_and_grad(
+                    jax.named_scope(LOSS_SCOPE)(loss_fn))(params, batch)
             return jax.value_and_grad(run_loss)(params, batch, scale)
         from repro.models._unroll import scan_or_unroll
         # split as (b/m, m) then move m to the front: microbatch i takes the
@@ -174,6 +192,7 @@ def _select_step(finite, new_tree, old_tree):
                         new_tree, old_tree)
 
 
+@jax.named_scope(EXCHANGE_SCOPE)
 def _synced(exchanger: Exchanger, params, opt_state, step, sync_every: int):
     """Apply the exchange, every step or gated every ``sync_every`` steps."""
     if sync_every == 1:
@@ -195,6 +214,7 @@ def _synced(exchanger: Exchanger, params, opt_state, step, sync_every: int):
     return params, opt_state
 
 
+@jax.named_scope(EXCHANGE_SCOPE)
 def _delayed_synced(exchanger: Exchanger, prev_params, prev_opt,
                     new_params, new_opt, aux, step, sync_every: int):
     """One-step-stale overlapped exchange (delay=1).
@@ -315,9 +335,10 @@ def make_param_avg_step(loss_fn: Callable, optimizer: Optimizer,
                           state.opt_state)
         b0 = jax.tree.map(lambda x: x[0], batch)
         loss, grads = loss_and_grad(p0, b0, scale)
-        loss, grads, finite = _unscaled(loss, grads, scale)
-        updates, o0 = optimizer.update(grads, o0, p0, lr)
-        p0 = apply_updates(p0, updates)
+        with jax.named_scope(UPDATE_SCOPE):
+            loss, grads, finite = _unscaled(loss, grads, scale)
+            updates, o0 = optimizer.update(grads, o0, p0, lr)
+            p0 = apply_updates(p0, updates)
         params = jax.tree.map(lambda x: x[None], p0)
         opt_state = jax.tree.map(
             lambda x: x[None] if x.ndim > 0 else x, o0)
@@ -347,9 +368,11 @@ def make_param_avg_step(loss_fn: Callable, optimizer: Optimizer,
                                  state.opt_state)
                 b = jax.tree.map(lambda x: x[ri], batch)
                 loss, grads = loss_and_grad(p, b, scale)
-                loss, grads, finite = _unscaled(loss, grads, scale)
-                updates, o = optimizer.update(grads, o, p, lr)
-                outs.append((apply_updates(p, updates), o, loss, finite))
+                with jax.named_scope(UPDATE_SCOPE):
+                    loss, grads, finite = _unscaled(loss, grads, scale)
+                    updates, o = optimizer.update(grads, o, p, lr)
+                    outs.append((apply_updates(p, updates), o, loss,
+                                 finite))
             params = jax.tree.map(lambda *xs: jnp.stack(xs),
                                   *[t[0] for t in outs])
             # scalar opt leaves are replica-identical bookkeeping; keep
@@ -366,17 +389,19 @@ def make_param_avg_step(loss_fn: Callable, optimizer: Optimizer,
             in_axes=(0, 0))(state.params, batch)
         loss = jnp.mean(losses)
         finite = jnp.asarray(True)
-        if scale is not None:
-            inv = 1.0 / scale
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32) * inv,
-                                 grads)
-            # the (R, ...) grads reduce to ONE flag — the AND over replicas
-            loss, finite = loss * inv, all_finite(grads)
-        # 2) independent per-replica optimizer updates
-        updates, opt_state = jax.vmap(
-            lambda g, s, p: optimizer.update(g, s, p, lr))(
-                grads, state.opt_state, state.params)
-        params = jax.vmap(apply_updates)(state.params, updates)
+        with jax.named_scope(UPDATE_SCOPE):
+            if scale is not None:
+                inv = 1.0 / scale
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) * inv, grads)
+                # the (R, ...) grads reduce to ONE flag — the AND over
+                # replicas
+                loss, finite = loss * inv, all_finite(grads)
+            # 2) independent per-replica optimizer updates
+            updates, opt_state = jax.vmap(
+                lambda g, s, p: optimizer.update(g, s, p, lr))(
+                    grads, state.opt_state, state.params)
+            params = jax.vmap(apply_updates)(state.params, updates)
         return params, opt_state, loss, finite
 
     def step(state: TrainState, batch) -> tuple:
@@ -406,9 +431,11 @@ def make_param_avg_step(loss_fn: Callable, optimizer: Optimizer,
         ns = state.numerics
         if scaling:
             # poisoned step: keep the incoming state, halve the scale
-            params = _select_step(finite, params, state.params)
-            opt_state = _select_step(finite, opt_state, state.opt_state)
-            ns = next_loss_scale_state(numerics, ns, finite)
+            with jax.named_scope(UPDATE_SCOPE):
+                params = _select_step(finite, params, state.params)
+                opt_state = _select_step(finite, opt_state,
+                                         state.opt_state)
+                ns = next_loss_scale_state(numerics, ns, finite)
 
         if delay == 0:
             params, opt_state = _synced(exchanger, params, opt_state,
@@ -503,22 +530,23 @@ def make_mesh_param_avg_step(loss_fn: Callable, optimizer: Optimizer,
         loss, grads = loss_and_grad(p_prev, b0, scale)
         ns = state.numerics
         finite = None
-        if scaling:
-            inv = 1.0 / scale
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32) * inv,
-                                 grads)
-            loss = loss * inv
-            # the skip decision must be identical on every shard: AND the
-            # local flags across the replica axes (min over 0/1 ints)
-            finite = jax.lax.pmin(
-                all_finite(grads).astype(jnp.int32), axis) > 0
-        updates, o0 = optimizer.update(grads, o_prev, p_prev, lr)
-        p0 = apply_updates(p_prev, updates)
-        if scaling:
-            # poisoned step: keep the incoming state, halve the scale
-            p0 = _select_step(finite, p0, p_prev)
-            o0 = _select_step(finite, o0, o_prev)
-            ns = next_loss_scale_state(numerics, ns, finite)
+        with jax.named_scope(UPDATE_SCOPE):
+            if scaling:
+                inv = 1.0 / scale
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) * inv, grads)
+                loss = loss * inv
+                # the skip decision must be identical on every shard: AND
+                # the local flags across the replica axes (min over 0/1)
+                finite = jax.lax.pmin(
+                    all_finite(grads).astype(jnp.int32), axis) > 0
+            updates, o0 = optimizer.update(grads, o_prev, p_prev, lr)
+            p0 = apply_updates(p_prev, updates)
+            if scaling:
+                # poisoned step: keep the incoming state, halve the scale
+                p0 = _select_step(finite, p0, p_prev)
+                o0 = _select_step(finite, o0, o_prev)
+                ns = next_loss_scale_state(numerics, ns, finite)
         aux = state.exchange
         if delay == 0:
             p0, o0 = _synced(exchanger, p0, o0, state.step, sync_every)
@@ -536,7 +564,8 @@ def make_mesh_param_avg_step(loss_fn: Callable, optimizer: Optimizer,
         opt_state = jax.tree.map(
             lambda new, old: new[None] if old.ndim > new.ndim else new,
             o0, state.opt_state)
-        loss = jax.lax.pmean(loss, axis)
+        with jax.named_scope(EXCHANGE_SCOPE):
+            loss = jax.lax.pmean(loss, axis)
         return TrainState(params, opt_state, state.step + 1, aux, ns), loss
 
     def step(state: TrainState, batch) -> tuple:
@@ -596,19 +625,21 @@ def make_grad_avg_step(loss_fn: Callable, optimizer: Optimizer,
         loss, grads = loss_and_grad(state.params, batch, scale)
         ns = state.numerics
         finite = None
-        if scaling:
-            inv = 1.0 / scale
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32) * inv,
-                                 grads)
-            loss = loss * inv
-            finite = all_finite(grads)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params, lr)
-        params = apply_updates(state.params, updates)
-        if scaling:
-            params = _select_step(finite, params, state.params)
-            opt_state = _select_step(finite, opt_state, state.opt_state)
-            ns = next_loss_scale_state(numerics, ns, finite)
+        with jax.named_scope(UPDATE_SCOPE):
+            if scaling:
+                inv = 1.0 / scale
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) * inv, grads)
+                loss = loss * inv
+                finite = all_finite(grads)
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params, lr)
+            params = apply_updates(state.params, updates)
+            if scaling:
+                params = _select_step(finite, params, state.params)
+                opt_state = _select_step(finite, opt_state,
+                                         state.opt_state)
+                ns = next_loss_scale_state(numerics, ns, finite)
         return TrainState(params, opt_state, state.step + 1,
                           state.exchange, ns), loss
 

@@ -29,17 +29,32 @@ overlap timeline in docs/architecture.md draws.
 Both loaders expose stall observability: ``last_wait_ms`` (time the
 trainer spent blocked in ``next()`` for the most recent batch) and
 ``wait_ms_total`` — the session logs these per step as ``stage_wait_ms``.
+
+Each stage runs inside an ``obs.span`` (the profiler's trace and the
+``obs`` registry), numbered by ``batch``, the batch's sequence number
+from the moment the loader pulls it from its source:
+
+    repro.data.fetch        ``next(source)``: everything the stream does,
+                            preprocessing mapped into it included
+    repro.data.device_put   the host part of the put
+    repro.data.queue_put    the loader waiting on a full queue (near zero:
+                            the loader sets the pace)
+    repro.data.fence_wait   the pinned loader waiting on a slot's fence
+    repro.data.wait         the trainer blocked in ``next()``; its reading
+                            is ``last_wait_ms``
 """
 from __future__ import annotations
 
 import collections
+import itertools
 import queue
 import threading
-import time
 from typing import Callable, Iterator, Optional
 
 import jax
 import numpy as np
+
+from repro import obs
 
 
 class PrefetchLoader:
@@ -67,18 +82,26 @@ class PrefetchLoader:
         self._thread: Optional[threading.Thread] = None
         self.last_wait_ms = 0.0      # trainer stall for the latest batch
         self.wait_ms_total = 0.0
+        self._consumed = 0           # sequence number of the next batch out
         if prefetch > 0:
             self._thread = threading.Thread(target=self._worker, daemon=True)
             self._thread.start()
 
     def _worker(self):
         try:
-            for batch in self._source:
+            for n in itertools.count():
+                try:
+                    batch = _fetch(self._source, n)
+                except StopIteration:
+                    break
                 if self._stop.is_set():
                     return
-                staged = self._device_put(self._preprocess(batch))
-                if not self._put(staged):
-                    return
+                host = self._preprocess(batch)
+                with obs.span("repro.data.device_put", batch=n):
+                    staged = self._device_put(host)
+                with obs.span("repro.data.queue_put", batch=n):
+                    if not self._put(staged):
+                        return
             self._put(_SENTINEL)
         except Exception as e:                      # surface in consumer
             self._put(_ExcBox(e))
@@ -102,11 +125,22 @@ class PrefetchLoader:
             # after close() the queue is drained and the worker dead — a
             # bare q.get() would block forever (the seed's hang)
             raise RuntimeError("PrefetchLoader is closed")
-        t0 = time.perf_counter()
-        if self._prefetch == 0:
-            out = self._device_put(self._preprocess(next(self._source)))
-            self._record_wait(t0)    # serial: the whole stage is a stall
-            return out
+        n = self._consumed
+        with obs.span("repro.data.wait", batch=n) as wait:
+            if self._prefetch == 0:
+                # serial: the whole stage is a stall
+                host = self._preprocess(_fetch(self._source, n))
+                with obs.span("repro.data.device_put", batch=n):
+                    out = self._device_put(host)
+            else:
+                out = self._get()
+        self.last_wait_ms = wait.ms
+        self.wait_ms_total += wait.ms
+        self._consumed += 1
+        return out
+
+    def _get(self):
+        """The next staged batch off the queue."""
         if self._done:
             raise StopIteration      # sentinel already consumed once
         while True:
@@ -128,12 +162,7 @@ class PrefetchLoader:
             raise StopIteration
         if isinstance(item, _ExcBox):
             raise item.exc
-        self._record_wait(t0)
         return item
-
-    def _record_wait(self, t0: float):
-        self.last_wait_ms = (time.perf_counter() - t0) * 1e3
-        self.wait_ms_total += self.last_wait_ms
 
     def fence(self, token):
         """No-op: queue handoff never reuses buffers, so there is nothing
@@ -157,6 +186,13 @@ class PrefetchLoader:
 
 _SENTINEL = object()
 _CLOSED = object()
+
+
+def _fetch(source: Iterator, n: int):
+    """``next(source)`` as batch ``n``, under its span (StopIteration
+    passes through, and records no span)."""
+    with obs.span("repro.data.fetch", batch=n):
+        return next(source)
 
 
 class _ExcBox:
@@ -205,7 +241,7 @@ class StagedPinnedLoader:
         self._stop = threading.Event()
         self.last_wait_ms = 0.0
         self.wait_ms_total = 0.0
-        self.fence_wait_ms_total = 0.0       # worker-side, off critical path
+        self._consumed = 0
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._thread.start()
 
@@ -222,7 +258,11 @@ class StagedPinnedLoader:
     def _worker(self):
         try:
             s = 0
-            for batch in self._source:
+            for n in itertools.count():
+                try:
+                    batch = _fetch(self._source, n)
+                except StopIteration:
+                    break
                 if self._stop.is_set():
                     return
                 host = self._preprocess(batch)
@@ -230,28 +270,33 @@ class StagedPinnedLoader:
                 if tok is _CLOSED:
                     return
                 if tok is not None:
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(tok)   # step done => reads done
-                    self.fence_wait_ms_total += \
-                        (time.perf_counter() - t0) * 1e3
-                dst = self._host[s]
-                leaves = jax.tree.leaves(host)
-                if dst is None or len(jax.tree.leaves(dst)) != len(leaves) \
-                        or any(d.shape != x.shape or d.dtype != x.dtype
-                               for d, x in zip(jax.tree.leaves(dst),
-                                               leaves)):
-                    # first lap (or a ragged final batch): allocate fresh
-                    dst = jax.tree.map(
-                        lambda x: np.empty(x.shape, x.dtype), host)
-                    self._host[s] = dst
-                jax.tree.map(lambda d, x: np.copyto(d, x), dst, host)
-                staged = self._device_put(dst)
-                if not self._put((s, staged)):
+                    # worker-side, off the critical path
+                    with obs.span("repro.data.fence_wait", batch=n):
+                        jax.block_until_ready(tok)  # step done => reads done
+                if not self._stage(s, n, host):
                     return
                 s = (s + 1) % self._slots
             self._put(_SENTINEL)
         except Exception as e:                  # surface in consumer
             self._put(_ExcBox(e))
+
+    def _stage(self, s: int, n: int, host) -> bool:
+        """Copy ``host`` into slot ``s``'s buffer, put it, and enqueue it
+        as batch ``n``; False once closed."""
+        with obs.span("repro.data.device_put", batch=n):
+            dst = self._host[s]
+            leaves = jax.tree.leaves(host)
+            if dst is None or len(jax.tree.leaves(dst)) != len(leaves) \
+                    or any(d.shape != x.shape or d.dtype != x.dtype
+                           for d, x in zip(jax.tree.leaves(dst), leaves)):
+                # first lap (or a ragged final batch): allocate fresh
+                dst = jax.tree.map(
+                    lambda x: np.empty(x.shape, x.dtype), host)
+                self._host[s] = dst
+            jax.tree.map(lambda d, x: np.copyto(d, x), dst, host)
+            staged = self._device_put(dst)
+        with obs.span("repro.data.queue_put", batch=n):
+            return self._put((s, staged))
 
     def _put(self, item) -> bool:
         while not self._stop.is_set():
@@ -271,7 +316,16 @@ class StagedPinnedLoader:
             raise RuntimeError("StagedPinnedLoader is closed")
         if self._done:
             raise StopIteration
-        t0 = time.perf_counter()
+        with obs.span("repro.data.wait", batch=self._consumed) as wait:
+            slot, staged = self._get()
+        self._handout.append(slot)
+        self.last_wait_ms = wait.ms
+        self.wait_ms_total += wait.ms
+        self._consumed += 1
+        return staged
+
+    def _get(self):
+        """The next ``(slot, staged batch)`` off the queue."""
         while True:
             try:
                 item = self._q.get(timeout=0.1)
@@ -293,11 +347,7 @@ class StagedPinnedLoader:
             raise StopIteration
         if isinstance(item, _ExcBox):
             raise item.exc
-        slot, staged = item
-        self._handout.append(slot)
-        self.last_wait_ms = (time.perf_counter() - t0) * 1e3
-        self.wait_ms_total += self.last_wait_ms
-        return staged
+        return item
 
     def fence(self, token):
         """Mark the oldest un-fenced batch's slot reusable once ``token``

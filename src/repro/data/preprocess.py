@@ -2,11 +2,15 @@
 subtract the mean image, random crop, random horizontal flip.
 
 Pure numpy, run on the host inside the loader thread (mirroring the paper's
-separate loading process).  Deterministic given the seed.
+separate loading process).  Deterministic given the seed.  Each transform
+runs inside an ``obs.span``: ``repro.data.mean_subtract`` and
+``repro.data.crop_flip``.
 """
 from __future__ import annotations
 
 import numpy as np
+
+from repro import obs
 
 
 def random_crop_flip(images: np.ndarray, crop: int, rng: np.random.Generator,
@@ -38,23 +42,25 @@ def random_crop_flip(images: np.ndarray, crop: int, rng: np.random.Generator,
     do_flip = rng.random(b) < 0.5 if flip else np.zeros(b, bool)
     if impl == "auto":
         impl = "loop"
-    if impl == "loop":
-        out = np.empty((b, crop, crop, c), images.dtype)
-        for i in range(b):
-            patch = images[i, ys[i]:ys[i] + crop, xs[i]:xs[i] + crop]
-            out[i] = patch[:, ::-1] if do_flip[i] else patch
-        return out
-    if impl == "gather":
-        rows = ys[:, None] + np.arange(crop)[None, :]           # (B, crop)
-        cols = xs[:, None] + np.arange(crop)[None, :]           # (B, crop)
-        cols = np.where(do_flip[:, None], cols[:, ::-1], cols)  # flip = rev W
-        return images[np.arange(b)[:, None, None],
-                      rows[:, :, None], cols[:, None, :]]
+    with obs.span("repro.data.crop_flip"):
+        if impl == "loop":
+            out = np.empty((b, crop, crop, c), images.dtype)
+            for i in range(b):
+                patch = images[i, ys[i]:ys[i] + crop, xs[i]:xs[i] + crop]
+                out[i] = patch[:, ::-1] if do_flip[i] else patch
+            return out
+        if impl == "gather":
+            rows = ys[:, None] + np.arange(crop)[None, :]       # (B, crop)
+            cols = xs[:, None] + np.arange(crop)[None, :]       # (B, crop)
+            cols = np.where(do_flip[:, None], cols[:, ::-1], cols)  # rev W
+            return images[np.arange(b)[:, None, None],
+                          rows[:, :, None], cols[:, None, :]]
     raise ValueError(f"unknown crop impl {impl!r} (loop|gather|auto)")
 
 
 def subtract_mean(images: np.ndarray, mean_image: np.ndarray) -> np.ndarray:
-    return images.astype(np.float32) - mean_image.astype(np.float32)
+    with obs.span("repro.data.mean_subtract"):
+        return images.astype(np.float32) - mean_image.astype(np.float32)
 
 
 def make_image_preprocess(mean_image: np.ndarray, crop: int, seed: int = 0):

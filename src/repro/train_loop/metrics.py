@@ -7,13 +7,25 @@ One line per event, ``kind`` discriminated:
     {"kind": "eval", "step": 10, "loss": 4.1, "top1_err": 0.87,
      "lr_dropped": false}
     {"kind": "summary", "steps": 100, "images_per_sec": 2612.0,
-     "step_ms_p50": 12.2, "step_ms_p90": 13.0, "step_ms_p99": 19.8, ...}
+     "step_ms_p50": 12.2, "step_ms_p90": 13.0, "step_ms_p99": 19.8,
+     "loader": {"fetch": 31.2, "mean_subtract": 12.5, "crop_flip": 15.1,
+                "device_put": 4.0, "queue_put": 20.3, "wait": 0.1}, ...}
 
 ``images_per_sec`` is the paper's Table 1 unit (for LM archs it carries
-sequences/sec — same field, the batch item is a sequence).  The trace is
-the session's single source of truth: tests diff resumed-vs-uninterrupted
-``train`` lines bit-exactly, and ``benchmarks/session_throughput.py`` turns
-the ``summary`` line into benchmark rows.
+sequences/sec — same field, the batch item is a sequence).
+
+``loader`` is the mean milliseconds per batch of each loader stage over
+the session (``obs.mean_ms`` of the ``repro.data.*`` spans,
+``data/pipeline.py``): ``fetch`` (the stream: source and preprocessing),
+``mean_subtract`` and ``crop_flip`` (inside it), ``device_put``,
+``queue_put`` (the loader waiting on a full queue), ``fence_wait``
+(pinned staging) and ``wait`` (the trainer blocked on the loader).  A
+stage that never ran is absent.
+
+The trace is the session's single source of truth: tests diff
+resumed-vs-uninterrupted ``train`` lines bit-exactly, and
+``benchmarks/session_throughput.py`` turns the ``summary`` line into
+benchmark rows.
 
 On resume, entries past the restored step are dropped (they came from the
 killed run's un-checkpointed tail) and the file continues in place, so one
@@ -131,9 +143,10 @@ class MetricsWriter:
         self._write({"kind": "eval", "step": step, **metrics,
                      "lr_dropped": lr_dropped})
 
-    def summary(self, steps: int) -> dict:
+    def summary(self, steps: int, loader: Optional[dict] = None) -> dict:
         """Table-1-format rollup over this process's timed steps (excludes
-        the compile step — callers time steady-state only)."""
+        the compile step — callers time steady-state only).  ``loader``:
+        mean ms per batch of each loader stage (module docstring)."""
         ts = sorted(self._times_ms)
         total_s = sum(ts) / 1e3
         out = {"kind": "summary", "steps": steps,
@@ -152,6 +165,8 @@ class MetricsWriter:
             out["loss_scale"] = self._last_scale
         if self._last_skipped is not None:
             out["skipped_steps"] = self._last_skipped
+        if loader:
+            out["loader"] = {k: round(v, 3) for k, v in loader.items()}
         self._write(out)
         return out
 

@@ -44,7 +44,7 @@ from typing import Any, Callable, Optional
 
 import jax
 
-from repro import checkpoint
+from repro import checkpoint, obs
 from repro.optim import schedules
 from repro.train_loop.eval import run_eval, take
 from repro.train_loop.metrics import MetricsWriter
@@ -204,6 +204,7 @@ class TrainSession:
         loader = None
         compiling = True                  # first call of each jit compiles
         t_session = time.perf_counter()
+        spans0 = obs.snapshot()
         try:
             stream = self.make_stream()
             for _ in range(self._ff_batches):   # deterministic fast-forward
@@ -265,6 +266,8 @@ class TrainSession:
             if loader is not None:
                 loader.close()            # never leak the worker thread
             result.state = self.state     # original buffer was donated
-            result.summary = writer.summary(result.final_step)
+            result.summary = writer.summary(
+                result.final_step,
+                loader=obs.mean_ms(spans0, obs.snapshot(), "repro.data."))
             writer.close()
         return result

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st
 
+from repro import obs
 from repro.data import PrefetchLoader, synthetic
 from repro.data.preprocess import (make_image_preprocess, random_crop_flip,
                                    subtract_mean)
@@ -136,6 +137,33 @@ def test_worker_exception_propagates():
     with pytest.raises(ValueError, match="boom"):
         next(ld)
         next(ld)
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_loader_spans_once_per_batch(prefetch):
+    """Every stage of every batch lands in the obs registry once: the
+    stream's fetch (preprocessing mapped into it, as the trainer and the
+    benchmark map it), the mean subtraction and crop inside it, the put
+    and the trainer's wait; ``wait_ms_total`` is the sum of the waits."""
+    rng = np.random.default_rng(0)
+    images = rng.integers(0, 256, (5, 4, 12, 12, 3), dtype=np.uint8)
+    prep = make_image_preprocess(np.full((12, 12, 3), 128.0), crop=8)
+    stream = map(prep, ({"images": im} for im in images))
+    before = obs.snapshot()
+    ld = PrefetchLoader(stream, prefetch=prefetch)
+    assert [b["images"].shape for b in ld] == [(4, 8, 8, 3)] * 5
+    ld.close()
+    after = obs.snapshot()
+
+    def delta(name, key="n"):
+        k = f"repro.data.{name}.{key}"
+        return after.get(k, 0) - before.get(k, 0)
+
+    for stage in ("fetch", "mean_subtract", "crop_flip", "device_put",
+                  "wait"):
+        assert delta(stage) == 5, stage
+    assert delta("queue_put") == (5 if prefetch else 0)
+    assert ld.wait_ms_total == pytest.approx(delta("wait", "ms"), rel=1e-9)
 
 
 @settings(max_examples=20, deadline=None)

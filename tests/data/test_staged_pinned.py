@@ -14,6 +14,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.data import make_loader
 from repro.data.pipeline import PrefetchLoader, StagedPinnedLoader
 
@@ -88,6 +89,7 @@ def test_worker_blocks_on_unready_fence_token(monkeypatch):
         return orig(tok)
 
     monkeypatch.setattr(pl.jax, "block_until_ready", slow_ready)
+    before = obs.snapshot()
     ld = StagedPinnedLoader(counter_source(8))
     first = next(ld)
     ld.fence("slow")                     # slot 0 gated on the slow token
@@ -101,7 +103,9 @@ def test_worker_blocks_on_unready_fence_token(monkeypatch):
     gate.set()
     ld.fence(None)
     assert int(np.asarray(next(ld)["x"])[0, 0]) == 2
-    assert ld.fence_wait_ms_total > 200, ld.fence_wait_ms_total
+    key = "repro.data.fence_wait.ms"
+    fence_wait_ms = obs.snapshot()[key] - before.get(key, 0.0)
+    assert fence_wait_ms > 200, fence_wait_ms
     ld.close()
 
 

@@ -116,10 +116,10 @@ def test_faithful_lrn_compiles(one_chip, hwc):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_faithful_train_step_fits_one_chip(one_chip):
-    """The trainer's one-chip program — faithful AlexNet at 227², global
-    batch 256, reference engine with one replica — compiles with the
-    Pallas conv and LRN in it and fits one chip's HBM."""
+@pytest.fixture(scope="module")
+def faithful_one_chip(one_chip):
+    """The trainer's one-chip program, compiled: faithful AlexNet at 227²,
+    global batch 256, reference engine with one replica."""
     from repro.core import init_param_avg_state, make_param_avg_step
     from repro.models import alexnet
     from repro.optim import schedules
@@ -140,7 +140,13 @@ def test_faithful_train_step_fits_one_chip(one_chip):
     batch = {"images": _spec((1, 256, size, size, cfg.in_channels),
                              jnp.float32, one_chip),
              "labels": _spec((1, 256), jnp.int32, one_chip)}
-    compiled = jax.jit(step, donate_argnums=0).lower(state, batch).compile()
+    return jax.jit(step, donate_argnums=0).lower(state, batch).compile()
+
+
+def test_faithful_train_step_fits_one_chip(faithful_one_chip):
+    """The one-chip program compiles with the Pallas conv and LRN in it
+    and fits one chip's HBM."""
+    compiled = faithful_one_chip
     txt = compiled.as_text()
     # five conv kernels and two LRN kernels in the forward pass
     assert txt.count("tpu_custom_call") >= 7
@@ -150,11 +156,11 @@ def test_faithful_train_step_fits_one_chip(one_chip):
     assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one chip"
 
 
-def test_faithful_mesh_step_fits_four_chips(topo):
-    """The paper's four-GPU recipe on four described v5e chips: the mesh
-    engine (shard_map over ('data',), one replica per chip, 64 images
-    each, all_reduce exchange) compiles with the Pallas kernels in it,
-    fits each chip's HBM and exchanges through an all-reduce."""
+@pytest.fixture(scope="module")
+def faithful_four_chips(topo):
+    """The paper's four-GPU recipe on four described v5e chips, compiled:
+    the mesh engine (shard_map over ('data',), one replica per chip, 64
+    images each, all_reduce exchange)."""
     import numpy as np
     from jax.sharding import Mesh
     from repro.core import init_param_avg_state, make_mesh_param_avg_step
@@ -188,7 +194,13 @@ def test_faithful_mesh_step_fits_four_chips(topo):
         "images": jax.ShapeDtypeStruct(
             (n_rep, per_replica, size, size, cfg.in_channels), jnp.float32),
         "labels": jax.ShapeDtypeStruct((n_rep, per_replica), jnp.int32)})
-    compiled = jax.jit(step, donate_argnums=0).lower(state, batch).compile()
+    return jax.jit(step, donate_argnums=0).lower(state, batch).compile()
+
+
+def test_faithful_mesh_step_fits_four_chips(faithful_four_chips):
+    """The mesh program compiles with the Pallas kernels in it, fits each
+    chip's HBM and exchanges through an all-reduce."""
+    compiled = faithful_four_chips
     txt = compiled.as_text()
     assert txt.count("tpu_custom_call") >= 7
     assert "all-reduce" in txt
@@ -196,3 +208,34 @@ def test_faithful_mesh_step_fits_four_chips(topo):
     per_chip = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert per_chip < HBM_BYTES, f"{per_chip / 1e9:.2f} GB per chip"
+
+
+def _phases_and_kinds(hlo_text: str):
+    """``bench/``'s phase and op-kind classes of a compiled step."""
+    import sys
+    from pathlib import Path
+    bench = str(Path(__file__).resolve().parents[2] / "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import phases
+    import xplane
+    return phases.hlo_phases(hlo_text), xplane.hlo_kinds(hlo_text)
+
+
+@pytest.mark.parametrize("program", ["faithful_one_chip",
+                                     "faithful_four_chips"])
+def test_faithful_step_phases(program, request):
+    """Every op of the chip's program falls in a phase of the step: the
+    nine conv-grads in the backward, the five Pallas conv forwards in the
+    forward, the collectives in the exchange (four chips)."""
+    ph, kinds = _phases_and_kinds(
+        request.getfixturevalue(program).as_text())
+    by_kind = {}
+    for name, kind in kinds.items():
+        by_kind.setdefault(kind, []).append(ph[name])
+    assert by_kind["conv_grad"] == ["backward"] * 9
+    assert by_kind["conv_fwd"] == ["forward"] * 5
+    assert set(by_kind.get("collective", [])) <= {"exchange"}
+    assert ("collective" in by_kind) == (program == "faithful_four_chips")
+    assert "other" not in ph.values()
+    assert {"forward", "backward", "update"} <= set(ph.values())

@@ -85,3 +85,13 @@ def test_summary_excludes_compile_steps(tmp_path):
     recs = read_jsonl(str(p), "train")
     assert recs[0].get("compile") is True
     assert "images_per_sec" not in recs[0]
+
+def test_summary_carries_loader_stage_means(tmp_path):
+    p = tmp_path / "m.jsonl"
+    w = MetricsWriter(str(p), images_per_step=4)
+    w.train(1, 1.0, 0.1, 0.01)
+    s = w.summary(1, loader={"fetch": 12.34567, "wait": 0.5})
+    assert s["loader"] == {"fetch": 12.346, "wait": 0.5}
+    assert read_jsonl(str(p), "summary")[0]["loader"] == s["loader"]
+    assert "loader" not in MetricsWriter(None).summary(1, loader={})
+    w.close()

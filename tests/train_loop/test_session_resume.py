@@ -150,3 +150,18 @@ def test_bf16_session_logs_loss_scale(tmp_path):
     summ = [r for r in recs if r.get("kind") == "summary"]
     assert summ and summ[-1]["loss_scale"] == 2.0 ** 15
     assert summ[-1]["skipped_steps"] == 0
+
+
+def test_summary_reports_loader_stages(tmp_path):
+    """The summary line's ``loader`` field: mean ms per batch of each
+    loader stage over the session, from the ``repro.data.*`` spans."""
+    m = str(tmp_path / "m.jsonl")
+    run_cli(BASE + ["--steps", "3", "--metrics-out", m], devices=1)
+    summ = [json.loads(line) for line in open(m)][-1]
+    assert summ["kind"] == "summary"
+    loader = summ["loader"]
+    assert {"fetch", "mean_subtract", "crop_flip", "device_put",
+            "queue_put", "wait"} <= set(loader)
+    assert all(v >= 0 for v in loader.values())
+    # the preprocessing runs inside the stream's fetch
+    assert loader["fetch"] >= loader["mean_subtract"]
