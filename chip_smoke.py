@@ -208,7 +208,7 @@ def _mesh_vs_reference(seed, steps, train_args) -> None:
     import jax
     import numpy as np
     from repro.core import (ExchangeConfig, init_param_avg_state,
-                            make_param_avg_step, reshape_for_replicas)
+                            make_param_avg_step)
     from repro.launch import train
     from repro.numerics import get_policy
     from repro.optim.optimizers import for_numerics, get_optimizer
@@ -248,11 +248,10 @@ def _mesh_vs_reference(seed, steps, train_args) -> None:
     step = jax.jit(make_param_avg_step(
         build.loss, opt, train.make_controller(args).schedule(),
         strategy=exch, numerics=npol), donate_argnums=0)
-    stream = build.make_stream()
+    stream = build.make_stream(n_rep)
     ref_losses = []
     for _ in range(steps):
-        batch = reshape_for_replicas(next(stream), n_rep)
-        state, loss = step(state, jax.device_put(batch, dev0))
+        state, loss = step(state, jax.device_put(next(stream), dev0))
         ref_losses.append(float(loss))
     print(f"reference (vmap on {dev0}): losses {ref_losses}", flush=True)
 

@@ -3,22 +3,31 @@
 The paper runs a separate *loading process* that copies the next minibatch
 disk -> host -> GPU while the training process computes, handing off
 device-resident buffers "instantly".  The CPython-GIL/multi-process motivation
-does not apply here (preprocessing is numpy, which releases the GIL, and
-``jax.device_put`` is async), so the same overlap is achieved with a
-background thread and a depth-2 queue — a double buffer:
+does not apply here (the loader thread's host work is the fetch, puts and
+dispatches, which release the GIL while they copy or wait, and the
+preprocessing arithmetic runs on the device), so the same overlap is
+achieved with a background thread and a depth-2 queue — a double buffer:
 
     loader thread:   fetch -> preprocess -> device_put (async) ->- queue
     trainer thread:  queue ->- step(current)            (overlapped)
 
-``device_put`` returns immediately; the transfer overlaps the in-flight step
-exactly like the paper's staged copy.  Set ``prefetch=0`` to get the serial
-baseline (the paper's "Parallel loading: No" rows in Table 1).
+Where each stage runs: the fetch pulls the next host batch; the image
+preprocessing (``preprocess.make_image_preprocess``) draws its crops and
+mirrors on the host, puts the raw batch on the device and dispatches the
+program that crops, mirrors and subtracts the mean there, so the batch it
+hands on is already device-resident; ``device_put`` then stages the rest
+(labels) and places the batch on the step's layout.  ``device_put``
+returns immediately; the transfer overlaps the in-flight step exactly like
+the paper's staged copy.  Set ``prefetch=0`` to get the serial baseline
+(the paper's "Parallel loading: No" rows in Table 1).
 
 ``StagedPinnedLoader`` is the paper's Fig. 1 taken literally: instead of
 allocating fresh host+device arrays every batch (page faults on first touch
 — the dominant cost of a cold ``device_put``), the loader thread stages into
 a *rotating pair of preallocated host buffers* that the backend maps
-zero-copy.  Reusing a buffer that the in-flight step may still be reading
+zero-copy.  Only host (numpy) leaves are staged: a leaf that is already a
+``jax.Array`` (preprocessed on the device) passes through untouched.
+Reusing a buffer that the in-flight step may still be reading
 would corrupt the batch, so reuse is gated on a **fence**: after dispatching
 the step that consumed a batch, the trainer hands the loader any output
 token of that step (``loader.fence(loss)``); the worker blocks on the
@@ -35,10 +44,12 @@ Each stage runs inside an ``obs.span`` (the profiler's trace and the
 from the moment the loader pulls it from its source:
 
     repro.data.fetch        ``next(source)``: everything the stream does,
-                            preprocessing mapped into it included
+                            preprocessing mapped into it included (its
+                            own spans: ``crop_flip``, ``raw_put``,
+                            ``mean_subtract``, see ``preprocess``)
     repro.data.device_put   the host part of the put
-    repro.data.queue_put    the loader waiting on a full queue (near zero:
-                            the loader sets the pace)
+    repro.data.queue_put    the loader waiting on a full queue (near zero
+                            when the loader sets the pace)
     repro.data.fence_wait   the pinned loader waiting on a slot's fence
     repro.data.wait         the trainer blocked in ``next()``; its reading
                             is ``last_wait_ms``
@@ -63,8 +74,9 @@ class PrefetchLoader:
     Args:
       source: iterator yielding pytrees of numpy arrays.
       prefetch: queue depth (2 = classic double buffer; 0 = synchronous).
-      preprocess: host-side transform run in the loader thread (the paper's
-        mean-subtract / crop / flip happens here).
+      preprocess: transform run in the loader thread (the paper's
+        mean-subtract / crop / flip: ``make_image_preprocess`` dispatches
+        them to the device and returns a device-resident batch).
       device_put: function staging a host pytree onto device(s); defaults to
         ``jax.device_put`` (pass a sharded variant for multi-device).
     """
@@ -281,20 +293,19 @@ class StagedPinnedLoader:
             self._put(_ExcBox(e))
 
     def _stage(self, s: int, n: int, host) -> bool:
-        """Copy ``host`` into slot ``s``'s buffer, put it, and enqueue it
-        as batch ``n``; False once closed."""
+        """Copy ``host``'s numpy leaves into slot ``s``'s buffers, put the
+        batch, and enqueue it as batch ``n``; False once closed.  A
+        ``jax.Array`` leaf (preprocessed on the device) is already staged:
+        it passes through untouched, with no host round trip."""
         with obs.span("repro.data.device_put", batch=n):
             dst = self._host[s]
-            leaves = jax.tree.leaves(host)
-            if dst is None or len(jax.tree.leaves(dst)) != len(leaves) \
-                    or any(d.shape != x.shape or d.dtype != x.dtype
-                           for d, x in zip(jax.tree.leaves(dst), leaves)):
+            if dst is None or not _fits(dst, host):
                 # first lap (or a ragged final batch): allocate fresh
                 dst = jax.tree.map(
-                    lambda x: np.empty(x.shape, x.dtype), host)
+                    lambda x: _ON_DEVICE if isinstance(x, jax.Array)
+                    else np.empty(x.shape, x.dtype), host)
                 self._host[s] = dst
-            jax.tree.map(lambda d, x: np.copyto(d, x), dst, host)
-            staged = self._device_put(dst)
+            staged = self._device_put(jax.tree.map(_into_slot, dst, host))
         with obs.span("repro.data.queue_put", batch=n):
             return self._put((s, staged))
 
@@ -365,6 +376,28 @@ class StagedPinnedLoader:
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
+
+
+_ON_DEVICE = object()    # a slot's place for a leaf that needs no buffer
+
+
+def _fits(dst, host) -> bool:
+    """Whether slot buffers ``dst`` can take ``host`` as they are."""
+    if jax.tree.structure(dst) != jax.tree.structure(host):
+        return False
+    return all(d is _ON_DEVICE if isinstance(x, jax.Array)
+               else isinstance(d, np.ndarray) and d.shape == x.shape
+               and d.dtype == x.dtype
+               for d, x in zip(jax.tree.leaves(dst), jax.tree.leaves(host)))
+
+
+def _into_slot(d, x):
+    """A device-resident leaf as it is; a host leaf copied into its
+    slot's buffer ``d``."""
+    if isinstance(x, jax.Array):
+        return x
+    np.copyto(d, x)
+    return d
 
 
 def make_loader(source: Iterator, *, prefetch: int = 2,

@@ -66,7 +66,8 @@ class Build:
     cfg: object
     init: callable
     loss: callable                    # loss(params, batch) -> scalar
-    make_stream: callable             # () -> fresh host-batch iterator
+    make_stream: callable             # (replicas) -> fresh (R, B/R, ...)
+                                      # batch iterator
     make_eval_batches: callable       # () -> fresh held-out iterator
     eval_metric_fn: callable          # (params, batch) -> {name: scalar}
     plateau_metric: str               # the metric the LR controller tracks
@@ -108,9 +109,10 @@ def build_lm(args, numerics) -> Build:
             out["image_mask"] = mask
         return out
 
-    def make_stream():
-        return map(add_extras, synthetic.markov_lm(
-            cfg.vocab_size, args.batch, args.seq_len, seed=args.seed))
+    def make_stream(replicas):
+        return map(lambda b: reshape_for_replicas(add_extras(b), replicas),
+                   synthetic.markov_lm(cfg.vocab_size, args.batch,
+                                       args.seq_len, seed=args.seed))
 
     def make_eval_batches():
         # same Markov chain (table from args.seed), held-out sample path
@@ -153,14 +155,20 @@ def build_alexnet(args, error, numerics) -> Build:
         synthetic.blob_images(cfg.n_classes, args.batch, cfg.image_size + 8,
                               seed=args.seed + 1), 2)
 
-    def make_stream():
+    def make_stream(replicas):
         # fresh preprocess per stream: its RNG advances once per batch, so
-        # resume's fast-forward replays crops/flips exactly
+        # resume's fast-forward replays crops/flips exactly.  The replica
+        # split is a free view of the host batch, and the device program
+        # writes the split layout the step reads (no device reshape).
         prep = make_image_preprocess(mean, cfg.image_size, seed=args.seed)
-        return map(prep, synthetic.blob_images(
-            cfg.n_classes, args.batch, cfg.image_size + 8, seed=args.seed))
+        return map(prep, map(
+            lambda b: reshape_for_replicas(b, replicas), synthetic.blob_images(
+                cfg.n_classes, args.batch, cfg.image_size + 8,
+                seed=args.seed)))
 
     def make_eval_batches():
+        # unsplit (B, ...) batches; the session caches them, and the cached
+        # images stay on the default device
         es = args.seed + EVAL_SEED_OFFSET
         prep = make_image_preprocess(mean, cfg.image_size, seed=es)
         return map(prep, synthetic.blob_images(
@@ -433,8 +441,7 @@ def main(argv=None) -> Run:
 
     session = TrainSession(
         state=state, build_step=build_step,
-        make_stream=lambda: map(
-            lambda b: reshape_for_replicas(b, n_rep), build.make_stream()),
+        make_stream=lambda: build.make_stream(n_rep),
         controller=controller, steps=args.steps, device_put=put,
         sharding=sharding,
         eval_step=make_eval_step(build.eval_metric_fn)

@@ -8,8 +8,9 @@ One line per event, ``kind`` discriminated:
      "lr_dropped": false}
     {"kind": "summary", "steps": 100, "images_per_sec": 2612.0,
      "step_ms_p50": 12.2, "step_ms_p90": 13.0, "step_ms_p99": 19.8,
-     "loader": {"fetch": 31.2, "mean_subtract": 12.5, "crop_flip": 15.1,
-                "device_put": 4.0, "queue_put": 20.3, "wait": 0.1}, ...}
+     "loader": {"fetch": 31.2, "crop_flip": 0.1, "raw_put": 4.0,
+                "mean_subtract": 0.2, "device_put": 0.1, "queue_put": 20.3,
+                "wait": 0.1}, ...}
 
 ``images_per_sec`` is the paper's Table 1 unit (for LM archs it carries
 sequences/sec — same field, the batch item is a sequence).
@@ -17,7 +18,9 @@ sequences/sec — same field, the batch item is a sequence).
 ``loader`` is the mean milliseconds per batch of each loader stage over
 the session (``obs.mean_ms`` of the ``repro.data.*`` spans,
 ``data/pipeline.py``): ``fetch`` (the stream: source and preprocessing),
-``mean_subtract`` and ``crop_flip`` (inside it), ``device_put``,
+``crop_flip`` (the crop and mirror draws), ``raw_put`` (the raw batch's
+put) and ``mean_subtract`` (the device program's dispatch) inside it,
+``device_put``,
 ``queue_put`` (the loader waiting on a full queue), ``fence_wait``
 (pinned staging) and ``wait`` (the trainer blocked on the loader).  A
 stage that never ran is absent.

@@ -2,14 +2,15 @@
 properties (hypothesis)."""
 import time
 
+import jax
 import numpy as np
 import pytest
 from _hyp import given, settings, st
 
 from repro import obs
 from repro.data import PrefetchLoader, synthetic
-from repro.data.preprocess import (make_image_preprocess, random_crop_flip,
-                                   subtract_mean)
+from repro.data.preprocess import (make_image_preprocess, preprocess_on_device,
+                                   put_mean, random_crop_flip, subtract_mean)
 
 
 def counter_source(n, delay=0.0):
@@ -143,8 +144,9 @@ def test_worker_exception_propagates():
 def test_loader_spans_once_per_batch(prefetch):
     """Every stage of every batch lands in the obs registry once: the
     stream's fetch (preprocessing mapped into it, as the trainer and the
-    benchmark map it), the mean subtraction and crop inside it, the put
-    and the trainer's wait; ``wait_ms_total`` is the sum of the waits."""
+    benchmark map it), the draws, the raw batch's put and the program's
+    dispatch inside it, the put and the trainer's wait; ``wait_ms_total``
+    is the sum of the waits."""
     rng = np.random.default_rng(0)
     images = rng.integers(0, 256, (5, 4, 12, 12, 3), dtype=np.uint8)
     prep = make_image_preprocess(np.full((12, 12, 3), 128.0), crop=8)
@@ -159,11 +161,111 @@ def test_loader_spans_once_per_batch(prefetch):
         k = f"repro.data.{name}.{key}"
         return after.get(k, 0) - before.get(k, 0)
 
-    for stage in ("fetch", "mean_subtract", "crop_flip", "device_put",
-                  "wait"):
+    for stage in ("fetch", "mean_subtract", "crop_flip", "raw_put",
+                  "device_put", "wait"):
         assert delta(stage) == 5, stage
     assert delta("queue_put") == (5 if prefetch else 0)
     assert ld.wait_ms_total == pytest.approx(delta("wait", "ms"), rel=1e-9)
+
+
+class _ForcedMirror:
+    """A generator whose mirror draws are forced (``u`` 0.0: every image
+    mirrored, 1.0: none); crop draws come from ``default_rng(seed)``."""
+
+    def __init__(self, seed, u):
+        self._g, self._u = np.random.default_rng(seed), u
+
+    def integers(self, *args, **kw):
+        return self._g.integers(*args, **kw)
+
+    def random(self, n):
+        return np.full(n, self._u)
+
+
+def _staged_band(b, size, seed):
+    """uint8 pixels in the staged cell's band (126..129) and their float32
+    mean image, near 127.5."""
+    pix = np.random.default_rng(seed).integers(
+        126, 130, (b, size, size, 3), dtype=np.uint8)
+    return pix, pix.mean(axis=0, dtype=np.float64).astype(np.float32)
+
+
+def _trainer_blobs(b, crop, seed):
+    """The trainer's input: float32 blob images with an 8-pixel margin
+    round the crop, and their mean image (``launch/train.py``)."""
+    size = crop + 8
+    mean = synthetic.mean_image(synthetic.blob_images(4, b, size,
+                                                      seed=seed + 1), 2)
+    return next(synthetic.blob_images(4, b, size, seed=seed))["images"], mean
+
+
+@pytest.mark.parametrize("mirror", ["drawn", "on", "off"])
+@pytest.mark.parametrize("images,crop", [
+    ("staged_band", 24), ("trainer_blobs", 24), ("staged_band", 32)],
+    ids=["uint8-staged-band", "float32-trainer-margin", "crop-equals-size"])
+def test_device_preprocess_bit_identical_to_host(images, crop, mirror):
+    """The device program's batch equals the host reference
+    ``random_crop_flip(subtract_mean(x, mean), crop, rng)`` bit for bit,
+    from the same draws, mirroring drawn or forced either way."""
+    x, mean = (_staged_band(6, 32, 3) if images == "staged_band"
+               else _trainer_blobs(6, crop, 3))
+
+    def rng():
+        return (np.random.default_rng(11) if mirror == "drawn"
+                else _ForcedMirror(11, 0.0 if mirror == "on" else 1.0))
+
+    got = preprocess_on_device(x, put_mean(mean), crop, rng())
+    want = random_crop_flip(subtract_mean(x, mean), crop, rng())
+    assert isinstance(got, jax.Array) and got.dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(got), want)
+    if mirror != "drawn" and crop < x.shape[1]:
+        # the forced mirror shows: unmirrored, the first pixel column of
+        # the window is the left edge
+        keep = random_crop_flip(subtract_mean(x, mean), crop,
+                                _ForcedMirror(11, 1.0))
+        assert np.array_equal(want, keep) == (mirror == "off")
+
+
+def test_device_preprocess_leaves_the_generator_as_the_host_path():
+    """After a batch the generator's state is the host path's, so resume's
+    fast-forward replays the same crops; batch after batch the loader's
+    transform gives the host reference's numbers."""
+    x, mean = _staged_band(4, 32, 5)
+    r_dev, r_host = np.random.default_rng(9), np.random.default_rng(9)
+    preprocess_on_device(x, put_mean(mean), 24, r_dev)
+    random_crop_flip(subtract_mean(x, mean), 24, r_host)
+    assert r_dev.bit_generator.state == r_host.bit_generator.state
+    prep, ref = make_image_preprocess(mean, 24, seed=9), \
+        np.random.default_rng(9)
+    for i in range(3):
+        out = prep({"images": x[::-1] if i % 2 else x, "labels": i})
+        np.testing.assert_array_equal(
+            np.asarray(out["images"]), random_crop_flip(subtract_mean(
+                x[::-1] if i % 2 else x, mean), 24, ref))
+        assert out["labels"] == i
+
+
+def test_device_preprocess_keeps_leading_replica_dims():
+    """A batch split into replicas on the host, (R, B/R, H, W, C), comes
+    back as (R, B/R, crop, crop, C): the flat batch's numbers, from the
+    flat batch's draws."""
+    x, mean = _staged_band(6, 32, 4)
+    r_split, r_flat = np.random.default_rng(2), np.random.default_rng(2)
+    got = preprocess_on_device(x.reshape((2, 3) + x.shape[1:]),
+                               put_mean(mean), 24, r_split)
+    want = random_crop_flip(subtract_mean(x, mean), 24, r_flat)
+    assert got.shape == (2, 3, 24, 24, 3)
+    np.testing.assert_array_equal(np.asarray(got).reshape(want.shape), want)
+    assert r_split.bit_generator.state == r_flat.bit_generator.state
+
+
+def test_device_preprocess_rejects_a_mismatched_mean():
+    x, mean = _staged_band(2, 32, 0)
+    with pytest.raises(ValueError, match="mean image"):
+        preprocess_on_device(x, put_mean(mean[:16]), 24,
+                             np.random.default_rng(0))
+    with pytest.raises(ValueError, match="crop 40"):
+        preprocess_on_device(x, put_mean(mean), 40, np.random.default_rng(0))
 
 
 @settings(max_examples=20, deadline=None)

@@ -125,6 +125,30 @@ def test_buffers_reused_after_first_lap():
     ld.close()
 
 
+def test_device_leaves_pass_through_and_numpy_leaves_are_staged():
+    """A ``jax.Array`` leaf (a batch preprocessed on the device) comes out
+    as the same device buffer, with no host round trip and no slot
+    buffer; the numpy leaves beside it are still copied into the slot's
+    preallocated buffers, reused lap after lap."""
+    import jax.numpy as jnp
+    images = [jnp.full((2, 3), i, jnp.float32) for i in range(6)]
+    ld = StagedPinnedLoader({"images": im, "labels": np.full(2, i, np.int32)}
+                            for i, im in enumerate(images))
+    label_bufs = {}
+    for i, b in enumerate(ld):
+        assert b["images"].unsafe_buffer_pointer() == \
+            images[i].unsafe_buffer_pointer()
+        np.testing.assert_array_equal(np.asarray(b["labels"]), [i, i])
+        slot = ld._host[i % 2]
+        assert not isinstance(slot["images"], np.ndarray)
+        assert isinstance(slot["labels"], np.ndarray)
+        label_bufs.setdefault(i % 2, set()).add(id(slot["labels"]))
+        ld.fence(b["images"])
+        time.sleep(0.05)                 # let the worker re-stage
+    assert all(len(ids) == 1 for ids in label_bufs.values()), label_bufs
+    ld.close()
+
+
 def test_ragged_final_batch_reallocates():
     def ragged():
         yield {"x": np.zeros((4, 3), np.float32)}

@@ -116,6 +116,29 @@ def test_faithful_lrn_compiles(one_chip, hwc):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("hw,dtype,lead", [
+    pytest.param(256, jnp.uint8, (256,), id="staged-uint8-256"),
+    pytest.param(ALEXNET_FAITHFUL.image_size + 8, jnp.float32, (1, 256),
+                 id="trainer-float32-margin")])
+def test_image_preprocess_compiles(one_chip, hw, dtype, lead):
+    """The loader's device program (mean subtraction, crop, mirror) at
+    the faithful crop and batch 256 compiles, as the staged feed calls it
+    and as the trainer's one-replica stream does, and its own memory
+    stays within four batches' worth."""
+    from repro.data.preprocess import _crop_flip_subtract
+    cfg = ALEXNET_FAITHFUL
+    c, crop = cfg.in_channels, cfg.image_size
+    raw = _spec((256, hw, hw * c), dtype, one_chip)
+    mean = _spec((hw, hw * c), jnp.float32, one_chip)
+    draws = _spec((3, 256), jnp.int32, one_chip)
+    compiled = _crop_flip_subtract.lower(raw, mean, draws, crop=crop,
+                                         channels=c, lead=lead).compile()
+    assert compiled.out_info.shape == lead + (crop, crop, c)
+    mem = compiled.memory_analysis()
+    batch = 256 * crop * crop * c * 4
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 * batch
+
+
 @pytest.fixture(scope="module")
 def faithful_one_chip(one_chip):
     """The trainer's one-chip program, compiled: faithful AlexNet at 227²,

@@ -160,7 +160,7 @@ def test_summary_reports_loader_stages(tmp_path):
     summ = [json.loads(line) for line in open(m)][-1]
     assert summ["kind"] == "summary"
     loader = summ["loader"]
-    assert {"fetch", "mean_subtract", "crop_flip", "device_put",
+    assert {"fetch", "mean_subtract", "crop_flip", "raw_put", "device_put",
             "queue_put", "wait"} <= set(loader)
     assert all(v >= 0 for v in loader.values())
     # the preprocessing runs inside the stream's fetch
